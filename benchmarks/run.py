@@ -158,6 +158,7 @@ def bench_decode_hotpath() -> list:
     import jax.numpy as jnp
     from concurrent.futures import Future
     from repro.configs import get_config
+    from repro.kernels import ops
     from repro.kernels.flash_attention import flash_attention
     from repro.models import init_params, make_caches
     from repro.serving import EngineConfig, ServingEngine
@@ -174,7 +175,7 @@ def bench_decode_hotpath() -> list:
     for label, window in (("causal", None), ("window64", 64)):
         fa = jax.jit(lambda a, b, c, w=window: flash_attention(
             a, b, c, causal=True, window=w, bq=bq, bk=bk,
-            return_visits=True))
+            return_visits=True, interpret=not ops._on_tpu()))
         us = _timeit(lambda: jax.block_until_ready(fa(q, k, v)[0]))
         vis = int(np.asarray(fa(q, k, v)[1]).sum()) // q.shape[0]
         total = (S // bq) * (S // bk)
@@ -918,6 +919,8 @@ def main() -> None:
                     help="tiny shapes/iterations: exercise every perf-path "
                          "import and compile without the full timings")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.smoke:
         global SMOKE
         SMOKE = True

@@ -40,7 +40,7 @@ def _kernel(x_ref, w_ref, o_ref, acc_ref, *, n_k):
 
 @functools.partial(jax.jit,
                    static_argnames=("bm", "bn", "bk", "interpret"))
-def cache_matmul(x, w, *, bm=128, bn=128, bk=128, interpret=True):
+def cache_matmul(x, w, *, interpret, bm=128, bn=128, bk=128):
     """x: (M, K) @ w: (K, N) -> (M, N) in x.dtype, fp32 accumulation.
 
     M/N/K must be divisible by the block shape (pad at the ops layer).

@@ -83,7 +83,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, vis_ref, m_ref, l_ref, acc_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        cnt_ref[...] = jnp.zeros_like(cnt_ref)
+        cnt_ref[0] = 0
 
     @pl.when(ki_eff <= hi)
     def _live():
@@ -105,32 +105,31 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, vis_ref, m_ref, l_ref, acc_ref,
             mask &= k_pos < kv_len
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[...]                           # (bq, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1)
-        acc_ref[...] = (acc_ref[...] * corr[:, None]
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = (acc_ref[...] * corr
                         + jax.lax.dot_general(
                             p.astype(v_ref.dtype), v_ref[0],
                             (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32))
         m_ref[...] = m_new
-        cnt_ref[...] = cnt_ref[...] + 1
+        cnt_ref[0] = cnt_ref[0] + 1
 
     @pl.when(ki == n_vis - 1)
     def _done():
-        o_ref[0] = (acc_ref[...]
-                    / jnp.maximum(l_ref[...], 1e-30)[:, None]
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
                     ).astype(o_ref.dtype)
-        vis_ref[0, 0] = cnt_ref[0]
+        vis_ref[...] = jnp.full(vis_ref.shape, cnt_ref[0], jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "causal", "window", "softcap", "bq", "bk", "interpret", "return_visits",
     "kv_len"))
-def flash_attention(q, k, v, *, causal=True, window=None, softcap=None,
-                    bq=128, bk=128, interpret=True, return_visits=False,
+def flash_attention(q, k, v, *, interpret, causal=True, window=None,
+                    softcap=None, bq=128, bk=128, return_visits=False,
                     kv_len=None):
     """q: (BH, Sq, D); k/v: (BHkv, Skv, D), BH = BHkv * G. Sq % bq == 0,
     Skv % bk == 0 (pad at the ops layer). ``kv_len`` (static) is the real
@@ -138,7 +137,9 @@ def flash_attention(q, k, v, *, causal=True, window=None, softcap=None,
     (they are NOT hidden by the causal mask when causal=False) and
     fully-pad blocks are skipped. Returns (BH, Sq, D) in q.dtype; with
     ``return_visits`` also an int32 (BH, Sq//bq) count of KV blocks
-    actually scored per q-block (the block-skipping audit trail)."""
+    actually scored per q-block (the block-skipping audit trail), which
+    leaves the kernel as a lane-wide (1, 128) row per program so every
+    block stays aligned to the TPU's (8, 128) tiling."""
     BH, Sq, D = q.shape
     BHkv, Skv, _ = k.shape
     G = BH // BHkv
@@ -170,20 +171,20 @@ def flash_attention(q, k, v, *, causal=True, window=None, softcap=None,
         ],
         out_specs=[
             pl.BlockSpec((1, bq, D), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, 1), lambda bh, qi, ki: (bh, qi)),
+            pl.BlockSpec((1, 1, 1, 128), lambda bh, qi, ki: (bh, qi, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, Sq, D), q.dtype),
-            jax.ShapeDtypeStruct((BH, n_q), jnp.int32),
+            jax.ShapeDtypeStruct((BH, n_q, 1, 128), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),       # running max
-            pltpu.VMEM((bq,), jnp.float32),       # running denominator
+            pltpu.VMEM((bq, 1), jnp.float32),     # running max
+            pltpu.VMEM((bq, 1), jnp.float32),     # running denominator
             pltpu.VMEM((bq, D), jnp.float32),     # output accumulator
-            pltpu.VMEM((1,), jnp.int32),          # live-block visit counter
+            pltpu.SMEM((1,), jnp.int32),          # live-block visit counter
         ],
         interpret=interpret,
     )(q, k, v)
     if return_visits:
-        return out, visits
+        return out, visits[:, :, 0, 0]
     return out

@@ -47,7 +47,7 @@ def _kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, n_k):
 
 @functools.partial(jax.jit,
                    static_argnames=("bm", "bn", "bk", "interpret"))
-def int8_matmul(x, qw, scale, *, bm=128, bn=128, bk=128, interpret=True):
+def int8_matmul(x, qw, scale, *, interpret, bm=128, bn=128, bk=128):
     """x: (M, K) float @ qw: (K, N) int8, scale: (N,) f32 -> (M, N) x.dtype.
 
     Scales are broadcast as a (1, bn) block per N tile and applied at the

@@ -39,7 +39,7 @@ def _kernel(a_ref, b_ref, o_ref, h_ref, *, bs):
 
 
 @functools.partial(jax.jit, static_argnames=("bs", "interpret"))
-def rglru_scan(a, b, *, bs=256, interpret=True):
+def rglru_scan(a, b, *, interpret, bs=256):
     """a, b: (B, S, W) float32, S % bs == 0 -> h: (B, S, W)."""
     B, S, W = a.shape
     assert S % bs == 0

@@ -5,6 +5,9 @@ it — the deployable version of examples/serve_poc.py.
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b --smoke \
       --requests 8 --temperature 0.7 --stream
 
+``--smoke`` (the default) serves the reduced variant; ``--no-smoke`` serves
+the published widths (e.g. ``--arch qwen2-0.5b --no-smoke`` on a TPU).
+
 Decoder requests go through the v2 API (GenerationRequest -> RequestHandle
 -> GenerationResult) and are served by the step-level continuous-batching
 scheduler unless --no-continuous selects the batch-at-a-time worker.
@@ -16,6 +19,7 @@ import argparse
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCHS, get_config
 from repro.core.loadtest import format_table, run_ladder
 from repro.models import init_params
@@ -27,7 +31,10 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gector-base",
                     choices=ARCHS + ["gector-base"])
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="reduced config (default); --no-smoke serves the "
+                         "published widths")
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--ladder", type=int, nargs="*", default=None)
@@ -44,6 +51,7 @@ def main():
                     help="batch-at-a-time decoder worker (A/B baseline)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     if args.ckpt:
         params = restore(args.ckpt)["params"]

@@ -40,7 +40,7 @@ def test_flash_block_skipping_matches_ref(causal, window, softcap):
     v = jax.random.normal(R(2), (2, S, 32), jnp.float32)
     out, vis = flash_attention(q, k, v, causal=causal, window=window,
                                softcap=softcap, bq=bq, bk=bk,
-                               return_visits=True)
+                               return_visits=True, interpret=True)
     ref = flash_attention_ref(q, k, v, causal=causal, window=window,
                               softcap=softcap)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -58,7 +58,7 @@ def test_flash_causal_visits_about_half():
     k = jax.random.normal(R(1), (2, S, 32), jnp.float32)
     v = jax.random.normal(R(2), (2, S, 32), jnp.float32)
     _, vis = flash_attention(q, k, v, causal=True, bq=bq, bk=bk,
-                             return_visits=True)
+                             return_visits=True, interpret=True)
     total = (S // bq) * (S // bk)          # what the seed always visited
     visited = int(np.asarray(vis)[0].sum())
     assert visited == total * (1 + S // bk) / (2 * S // bk)  # 36 of 64
@@ -89,7 +89,8 @@ def test_decode_attention_early_out():
         kv_pos = jnp.broadcast_to(kv_pos, (BHkv, L))
         q_pos = jnp.full((BHkv, 1), valid - 1, jnp.int32)
         out, vis = decode_attention(q, k, v, q_pos, kv_pos, window=window,
-                                    bk=bk, return_visits=True)
+                                    bk=bk, return_visits=True,
+                                    interpret=True)
         ref = decode_attention_ref(q, k, v, q_pos[:, 0], kv_pos,
                                    window=window)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),

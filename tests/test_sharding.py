@@ -13,11 +13,10 @@ from repro.models import init_params, make_caches
 from repro.parallel.sharding import (MeshRules, param_partition_specs,
                                      rules_for, use_rules)
 
-pytestmark = pytest.mark.filterwarnings("ignore::DeprecationWarning")
-
 
 def _mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_host_mesh
+    return make_host_mesh()
 
 
 def _fake_rules(mesh=None, **kw):
