@@ -1,0 +1,6 @@
+"""idle_share.enc (%): share of the traced span in which no operation ran
+on the chip (1 - busy union / span), in a decoder cell."""
+
+
+def read(win):
+    return None if win.trace is None else 100.0 * win.trace.idle_share
