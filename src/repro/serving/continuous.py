@@ -64,12 +64,14 @@ import queue
 import time
 from typing import Dict, List, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.serving.api import (FINISH_CANCELLED, FINISH_EOS, FINISH_LENGTH,
                                GenerationResult, RequestTiming)
 from repro.serving import kvcache
+from repro.serving.clock import WorkerClock
 from repro.serving.kvcache import CachePool
 from repro.serving.scheduler import LaneQueue, pick_tier
 
@@ -151,8 +153,17 @@ class _Lane:
 
 
 class ContinuousScheduler:
+    # the worker's phases (``WorkerClock``), counters cont_<phase>_ns:
+    # wait (idle, blocked on the queue), admit (admission bookkeeping),
+    # dispatch (host arrays to the device, calls into jitted programs),
+    # device (blocked on a program's outputs), copy (outputs to numpy),
+    # publish (streaming tokens, retiring rows, resolving futures)
+    PHASES = ("wait", "admit", "dispatch", "device", "copy", "publish")
+
     def __init__(self, engine):
         self.eng = engine                # guarded-by: init
+        self.clock = WorkerClock(engine._stats, "cont",  # guarded-by: init
+                                 self.PHASES)
         # every lane exists up front (device pools stay lazy — see
         # _Lane.pool): the worker's idle/busy checks iterate this dict,
         # and lazily inserting lanes from warmup or client threads raced
@@ -173,8 +184,13 @@ class ContinuousScheduler:
                 try:
                     idle = not self.pending and not any(
                         l.busy for l in self.lanes.values())
-                    self._drain(block=idle)
-                    self._admit()
+                    if idle:
+                        with self.clock.phase("wait"):
+                            self._drain(block=True)
+                    with self.clock.phase("admit"):
+                        if not idle:
+                            self._drain(block=False)
+                        self._admit()
                     lane = self._next_lane()
                     if lane is not None:
                         self._step(lane)
@@ -309,47 +325,54 @@ class ContinuousScheduler:
                     r.future.set_exception(e)
 
     def _prefill_inner(self, claimed, lane: _Lane) -> None:  # holds: worker
-        eng = self.eng
+        eng, clock = self.eng, self.clock
         t0 = time.perf_counter()
         B, bucket, pool = len(claimed), lane.bucket, lane.pool
-        # gather acquire: one compiled variant per join size, not per slot
-        # run position (joins land at arbitrary offsets mid-serve)
-        slots, view = pool.acquire([id(r) for r in claimed], gather=True)
-        toks = np.zeros((B, bucket), np.int32)
-        lens = np.zeros(B, np.int32)
-        for i, r in enumerate(claimed):
-            r.t_start = t0
-            toks[i, :len(r.tokens)] = r.tokens
-            lens[i] = len(r.tokens)
-        temp, topk, seed, eos, budget, any_sample = \
-            eng._sampling_arrays(claimed)
-        sargs = ((jnp.asarray(temp), jnp.asarray(topk), jnp.asarray(seed))
-                 if any_sample else (None, None, None))
-        first, caches = eng._prefill_fn()(
-            eng.params, jnp.asarray(toks), jnp.asarray(lens), view, *sargs)
-        if eng.ec.spec_decode:
-            # spec install: verify chunks attend the whole ring, so the
-            # padded prefill tail (attn_apply stamps valid pos on every
-            # bucket position) must go back to the empty sentinel — the
-            # rollback fuses that truncation into the write-back
-            pool.scatter_rollback(slots, caches, [int(x) for x in lens],
-                                  lengths=[int(x) + 1 for x in lens])
-            self._draft_prefill(lane, claimed, slots)
-        else:
-            pool.write_back(slots, caches,
-                            lengths=[int(x) + 1 for x in lens])
-        first = np.asarray(first)
+        with clock.phase("dispatch"):
+            # gather acquire: one compiled variant per join size, not per
+            # slot run position (joins land at arbitrary offsets mid-serve)
+            slots, view = pool.acquire([id(r) for r in claimed], gather=True)
+            toks = np.zeros((B, bucket), np.int32)
+            lens = np.zeros(B, np.int32)
+            for i, r in enumerate(claimed):
+                r.t_start = t0
+                toks[i, :len(r.tokens)] = r.tokens
+                lens[i] = len(r.tokens)
+            temp, topk, seed, eos, budget, any_sample = \
+                eng._sampling_arrays(claimed)
+            sargs = ((jnp.asarray(temp), jnp.asarray(topk),
+                      jnp.asarray(seed)) if any_sample else (None, None, None))
+            first, caches = eng._prefill_fn()(
+                eng.params, jnp.asarray(toks), jnp.asarray(lens), view,
+                *sargs)
+            if eng.ec.spec_decode:
+                # spec install: verify chunks attend the whole ring, so the
+                # padded prefill tail (attn_apply stamps valid pos on every
+                # bucket position) must go back to the empty sentinel — the
+                # rollback fuses that truncation into the write-back
+                pool.scatter_rollback(slots, caches, [int(x) for x in lens],
+                                      lengths=[int(x) + 1 for x in lens])
+                self._draft_prefill(lane, claimed, slots)
+            else:
+                pool.write_back(slots, caches,
+                                lengths=[int(x) + 1 for x in lens])
+            for r, s in zip(claimed, slots):
+                # whole-prompt joins can still seed the store (a prompt of
+                # exactly one chunk is a storable boundary)
+                self._insert_prefix(lane, r, 0, s)
+        with clock.phase("device"):
+            jax.block_until_ready(first)
+        with clock.phase("copy"):
+            first = np.asarray(first)
         eng._stats["prefill_batches"] += 1
         t1 = time.perf_counter()
-        for i, (r, s) in enumerate(zip(claimed, slots)):
-            # whole-prompt joins can still seed the store (a prompt of
-            # exactly one chunk is a storable boundary)
-            self._insert_prefix(lane, r, 0, s)
-            r.t_prefill_done = t1
-            self._start_row(lane, r, s, int(first[i]), int(lens[i]),
-                            budget=int(budget[i]), eos=int(eos[i]),
-                            temp=float(temp[i]), topk=int(topk[i]),
-                            seed=int(seed[i]), now=t1)
+        with clock.phase("publish"):
+            for i, (r, s) in enumerate(zip(claimed, slots)):
+                r.t_prefill_done = t1
+                self._start_row(lane, r, s, int(first[i]), int(lens[i]),
+                                budget=int(budget[i]), eos=int(eos[i]),
+                                temp=float(temp[i]), topk=int(topk[i]),
+                                seed=int(seed[i]), now=t1)
 
     def _start_row(self, lane: _Lane, r, slot: int, tok: int, plen: int, *,  # holds: worker
                    budget: int, eos: int, temp: float, topk: int, seed: int,
@@ -393,57 +416,64 @@ class ContinuousScheduler:
                     r.future.set_exception(e)
 
     def _prefill_hits_inner(self, claimed, lane: _Lane) -> None:  # holds: worker
-        eng = self.eng
+        eng, clock = self.eng, self.clock
         store = eng._prefix_store(lane.bucket)
         C = eng.ec.prefill_chunk
         t0 = time.perf_counter()
         B, pool = len(claimed), lane.pool
         reqs = [r for r, _ in claimed]
-        # claim without reset: the load overwrites the slots fully
-        slots = pool.claim([id(r) for r in reqs])
-        try:
-            store.load_many([e for _, e in claimed], pool, slots)
-        finally:
-            for _, e in claimed:
-                store.release(e)
-        toks = np.zeros((B, C), np.int32)
-        start = np.zeros(B, np.int32)
-        nvalid = np.zeros(B, np.int32)
-        for i, (r, e) in enumerate(claimed):
-            r.t_start = t0
-            suffix = np.asarray(r.tokens)[e.n_tokens:]
-            toks[i, :len(suffix)] = suffix
-            start[i], nvalid[i] = e.n_tokens, len(suffix)
-        temp, topk, seed, eos, budget, any_sample = \
-            eng._sampling_arrays(reqs)
-        sargs = ((jnp.asarray(temp), jnp.asarray(topk), jnp.asarray(seed))
-                 if any_sample else (None, None, None))
-        first, caches = eng._chunk_fn()(
-            eng.params, jnp.asarray(toks), jnp.asarray(start),
-            jnp.asarray(nvalid), pool.batch_view(slots, gather=True),
-            *sargs)
-        if eng.ec.spec_decode:
-            # the suffix chunk's padded tail also stamps valid pos values
-            # past the prompt — truncate at install (see _prefill_inner)
-            pool.scatter_rollback(slots, caches,
-                                  [len(r.tokens) for r in reqs],
-                                  lengths=[len(r.tokens) + 1 for r in reqs])
-            self._draft_prefill(lane, reqs, slots)
-        else:
-            pool.write_back(slots, caches,
-                            lengths=[len(r.tokens) + 1 for r in reqs])
-        first = np.asarray(first)
+        with clock.phase("dispatch"):
+            # claim without reset: the load overwrites the slots fully
+            slots = pool.claim([id(r) for r in reqs])
+            try:
+                store.load_many([e for _, e in claimed], pool, slots)
+            finally:
+                for _, e in claimed:
+                    store.release(e)
+            toks = np.zeros((B, C), np.int32)
+            start = np.zeros(B, np.int32)
+            nvalid = np.zeros(B, np.int32)
+            for i, (r, e) in enumerate(claimed):
+                r.t_start = t0
+                suffix = np.asarray(r.tokens)[e.n_tokens:]
+                toks[i, :len(suffix)] = suffix
+                start[i], nvalid[i] = e.n_tokens, len(suffix)
+            temp, topk, seed, eos, budget, any_sample = \
+                eng._sampling_arrays(reqs)
+            sargs = ((jnp.asarray(temp), jnp.asarray(topk),
+                      jnp.asarray(seed)) if any_sample else (None, None, None))
+            first, caches = eng._chunk_fn()(
+                eng.params, jnp.asarray(toks), jnp.asarray(start),
+                jnp.asarray(nvalid), pool.batch_view(slots, gather=True),
+                *sargs)
+            if eng.ec.spec_decode:
+                # the suffix chunk's padded tail also stamps valid pos
+                # values past the prompt — truncate at install (see
+                # _prefill_inner)
+                pool.scatter_rollback(
+                    slots, caches, [len(r.tokens) for r in reqs],
+                    lengths=[len(r.tokens) + 1 for r in reqs])
+                self._draft_prefill(lane, reqs, slots)
+            else:
+                pool.write_back(slots, caches,
+                                lengths=[len(r.tokens) + 1 for r in reqs])
+            for (r, e), s in zip(claimed, slots):
+                # a prompt extending >= 1 chunk past its matched prefix is
+                # a new, deeper boundary worth storing (conversation growth)
+                self._insert_prefix(lane, r, e.n_tokens, s)
+        with clock.phase("device"):
+            jax.block_until_ready(first)
+        with clock.phase("copy"):
+            first = np.asarray(first)
         eng._stats["prefill_batches"] += 1
         t1 = time.perf_counter()
-        for i, ((r, e), s) in enumerate(zip(claimed, slots)):
-            # a prompt extending >= 1 chunk past its matched prefix is a
-            # new, deeper boundary worth storing (conversation growth)
-            self._insert_prefix(lane, r, e.n_tokens, s)
-            r.t_prefill_done = t1
-            self._start_row(lane, r, s, int(first[i]), len(r.tokens),
-                            budget=int(budget[i]), eos=int(eos[i]),
-                            temp=float(temp[i]), topk=int(topk[i]),
-                            seed=int(seed[i]), now=t1)
+        with clock.phase("publish"):
+            for i, (r, s) in enumerate(zip(reqs, slots)):
+                r.t_prefill_done = t1
+                self._start_row(lane, r, s, int(first[i]), len(r.tokens),
+                                budget=int(budget[i]), eos=int(eos[i]),
+                                temp=float(temp[i]), topk=int(topk[i]),
+                                seed=int(seed[i]), now=t1)
 
     def _insert_prefix(self, lane: _Lane, r, matched: int,  # holds: worker
                        slot: int) -> None:
@@ -483,8 +513,9 @@ class ContinuousScheduler:
             hit = [(ent, stg[i]) for i, ent in enumerate(entries)
                    if ent is not None]
             if hit:
-                store.load_many([ent for ent, _ in hit], staging,
-                                [s for _, s in hit])
+                with self.clock.phase("dispatch"):
+                    store.load_many([ent for ent, _ in hit], staging,
+                                    [s for _, s in hit])
             t0 = time.perf_counter()
             for i, r in enumerate(claimed):
                 r.t_start = t0
@@ -531,9 +562,10 @@ class ContinuousScheduler:
         for f in list(lane.fills):       # cancelled mid-prefill: retire
             h = f.req.handle
             if h is not None and h.cancel_requested:
-                self._release_fills(lane, [f])
-                f.req.t_prefill_done = now
-                self._resolve(f.req, [], FINISH_CANCELLED, now)
+                with self.clock.phase("publish"):
+                    self._release_fills(lane, [f])
+                    f.req.t_prefill_done = now
+                    self._resolve(f.req, [], FINISH_CANCELLED, now)
         if not lane.fills:
             return
         try:
@@ -546,35 +578,39 @@ class ContinuousScheduler:
                     f.req.future.set_exception(e)
 
     def _fill_chunk_inner(self, lane: _Lane) -> None:  # holds: worker
-        eng = self.eng
+        eng, clock = self.eng, self.clock
         C = eng.ec.prefill_chunk
         fills = list(lane.fills)
         B = len(fills)
         staging = lane.get_staging(eng)
-        toks = np.zeros((B, C), np.int32)
-        start = np.zeros(B, np.int32)
-        nvalid = np.zeros(B, np.int32)
-        temp = np.zeros(B, np.float32)
-        topk = np.zeros(B, np.int32)
-        seed = np.zeros(B, np.int32)
-        for i, f in enumerate(fills):
-            chunk = np.asarray(f.req.tokens)[f.filled:f.filled + C]
-            toks[i, :len(chunk)] = chunk
-            start[i], nvalid[i] = f.filled, len(chunk)
-            temp[i], topk[i], seed[i] = f.temp, f.topk, f.seed
-        any_sample = bool((temp > 0).any())
-        sargs = ((jnp.asarray(temp), jnp.asarray(topk), jnp.asarray(seed))
-                 if any_sample else (None, None, None))
-        stg_slots = [f.stg for f in fills]
-        first, caches = eng._chunk_fn()(
-            eng.params, jnp.asarray(toks), jnp.asarray(start),
-            jnp.asarray(nvalid), staging.batch_view(stg_slots, gather=True),
-            *sargs)
-        staging.write_back(
-            stg_slots, caches,
-            lengths=[f.filled + int(nvalid[i])
-                     for i, f in enumerate(fills)])
-        first = np.asarray(first)
+        with clock.phase("dispatch"):
+            toks = np.zeros((B, C), np.int32)
+            start = np.zeros(B, np.int32)
+            nvalid = np.zeros(B, np.int32)
+            temp = np.zeros(B, np.float32)
+            topk = np.zeros(B, np.int32)
+            seed = np.zeros(B, np.int32)
+            for i, f in enumerate(fills):
+                chunk = np.asarray(f.req.tokens)[f.filled:f.filled + C]
+                toks[i, :len(chunk)] = chunk
+                start[i], nvalid[i] = f.filled, len(chunk)
+                temp[i], topk[i], seed[i] = f.temp, f.topk, f.seed
+            any_sample = bool((temp > 0).any())
+            sargs = ((jnp.asarray(temp), jnp.asarray(topk),
+                      jnp.asarray(seed)) if any_sample else (None, None, None))
+            stg_slots = [f.stg for f in fills]
+            first, caches = eng._chunk_fn()(
+                eng.params, jnp.asarray(toks), jnp.asarray(start),
+                jnp.asarray(nvalid),
+                staging.batch_view(stg_slots, gather=True), *sargs)
+            staging.write_back(
+                stg_slots, caches,
+                lengths=[f.filled + int(nvalid[i])
+                         for i, f in enumerate(fills)])
+        with clock.phase("device"):
+            jax.block_until_ready(first)
+        with clock.phase("copy"):
+            first = np.asarray(first)
         eng._stats["prefill_chunks"] += B
         eng._lane_stat(lane.bucket)["prefill_chunks"] += B
         done = []
@@ -585,27 +621,33 @@ class ContinuousScheduler:
         if not done:
             return
         t1 = time.perf_counter()
-        # one scatter installs every completed prompt into its lane slot
-        done_slots = [f.slot for _, f in done]
-        src = staging.batch_view([f.stg for _, f in done], gather=True)
-        if eng.ec.spec_decode:
-            # the last staged chunk's padded tail carries valid pos values
-            # past the prompt — truncate at install (see _prefill_inner)
-            lane.pool.scatter_rollback(
-                done_slots, src, [f.filled for _, f in done],
-                lengths=[f.filled + 1 for _, f in done])
-            self._draft_prefill(lane, [f.req for _, f in done], done_slots)
-        else:
-            lane.pool.write_back(
-                done_slots, src, lengths=[f.filled + 1 for _, f in done])
-        for i, f in done:
-            lane.fills.remove(f)
-            staging.release(f.stg)
-            self._insert_prefix(lane, f.req, f.matched, f.slot)
-            f.req.t_prefill_done = t1
-            self._start_row(lane, f.req, f.slot, int(first[i]), f.filled,
-                            budget=f.budget, eos=f.eos, temp=f.temp,
-                            topk=f.topk, seed=f.seed, now=t1)
+        with clock.phase("dispatch"):
+            # one scatter installs every completed prompt into its lane slot
+            done_slots = [f.slot for _, f in done]
+            src = staging.batch_view([f.stg for _, f in done], gather=True)
+            if eng.ec.spec_decode:
+                # the last staged chunk's padded tail carries valid pos
+                # values past the prompt — truncate at install (see
+                # _prefill_inner)
+                lane.pool.scatter_rollback(
+                    done_slots, src, [f.filled for _, f in done],
+                    lengths=[f.filled + 1 for _, f in done])
+                self._draft_prefill(lane, [f.req for _, f in done],
+                                    done_slots)
+            else:
+                lane.pool.write_back(
+                    done_slots, src, lengths=[f.filled + 1 for _, f in done])
+            for _, f in done:
+                self._insert_prefix(lane, f.req, f.matched, f.slot)
+        with clock.phase("publish"):
+            for i, f in done:
+                lane.fills.remove(f)
+                staging.release(f.stg)
+                f.req.t_prefill_done = t1
+                self._start_row(lane, f.req, f.slot, int(first[i]),
+                                f.filled, budget=f.budget, eos=f.eos,
+                                temp=f.temp, topk=f.topk, seed=f.seed,
+                                now=t1)
 
     # ------------------------------------------------------ decode steps
     def _segment(self, lane: _Lane) -> None:  # holds: worker
@@ -633,41 +675,46 @@ class ContinuousScheduler:
         #                                  (metrics() iterates lock-free)
         now = time.perf_counter()
         pool = lane.pool
-        for j, s in enumerate(slots):
-            row = lane.rows[s]
-            new = toks[j][emits[j]].tolist()
-            row.toks.extend(new)
-            row.req.handle._push(new)
-            pool.lengths[s] = int(lane.pos[s]) + 1
-            if not st_active[j]:
-                self._finish(lane, row,
-                             FINISH_EOS if st_eos[j] else FINISH_LENGTH, now)
-            elif row.req.handle.cancel_requested:
-                self._finish(lane, row, FINISH_CANCELLED, now)
+        with self.clock.phase("publish"):
+            for j, s in enumerate(slots):
+                row = lane.rows[s]
+                new = toks[j][emits[j]].tolist()
+                row.toks.extend(new)
+                row.req.handle._push(new)
+                pool.lengths[s] = int(lane.pos[s]) + 1
+                if not st_active[j]:
+                    self._finish(lane, row, FINISH_EOS if st_eos[j]
+                                 else FINISH_LENGTH, now)
+                elif row.req.handle.cancel_requested:
+                    self._finish(lane, row, FINISH_CANCELLED, now)
 
     def _segment_full(self, lane: _Lane):  # holds: worker
         """Full-width segment over every pool slot (live rows plus inert
         free/prefilling slots) — today's fixed-width path, and the adaptive
         path's top tier. The pool caches are donated and swapped whole."""
-        eng = self.eng
+        eng, clock = self.eng, self.clock
         pool = lane.pool
-        any_sample = any(lane.temp[s] > 0 for s in lane.rows)
-        sargs = ((jnp.asarray(lane.temp), jnp.asarray(lane.topk),
-                  jnp.asarray(lane.seed)) if any_sample
-                 else (None, None, None))
-        toks, emits, state, caches = eng._segment_fn()(
-            eng.params, jnp.asarray(lane.last_tok[:, None]),
-            jnp.asarray(lane.pos[:, None]), pool.caches,
-            jnp.asarray(lane.active), jnp.asarray(lane.budget),
-            jnp.asarray(lane.eos), *sargs)
-        pool.caches = caches
-        toks, emits = np.asarray(toks), np.asarray(emits)
-        st_active = np.asarray(state["active"])
-        st_eos = np.asarray(state["eos_hit"])
-        lane.last_tok = np.asarray(state["tok"])[:, 0].copy()
-        lane.pos = np.asarray(state["pos"])[:, 0].copy()
-        lane.budget = np.asarray(state["budget"]).copy()
-        lane.active = st_active.copy()
+        with clock.phase("dispatch"):
+            any_sample = any(lane.temp[s] > 0 for s in lane.rows)
+            sargs = ((jnp.asarray(lane.temp), jnp.asarray(lane.topk),
+                      jnp.asarray(lane.seed)) if any_sample
+                     else (None, None, None))
+            toks, emits, state, caches = eng._segment_fn()(
+                eng.params, jnp.asarray(lane.last_tok[:, None]),
+                jnp.asarray(lane.pos[:, None]), pool.caches,
+                jnp.asarray(lane.active), jnp.asarray(lane.budget),
+                jnp.asarray(lane.eos), *sargs)
+            pool.caches = caches
+        with clock.phase("device"):
+            jax.block_until_ready((toks, emits, state))
+        with clock.phase("copy"):
+            toks, emits = np.asarray(toks), np.asarray(emits)
+            st_active = np.asarray(state["active"])
+            st_eos = np.asarray(state["eos_hit"])
+            lane.last_tok = np.asarray(state["tok"])[:, 0].copy()
+            lane.pos = np.asarray(state["pos"])[:, 0].copy()
+            lane.budget = np.asarray(state["budget"]).copy()
+            lane.active = st_active.copy()
         slots = list(lane.rows)
         return (slots, toks[slots], emits[slots], st_active[slots],
                 st_eos[slots])
@@ -680,33 +727,38 @@ class ContinuousScheduler:
         and are never scattered back, so pool slots outside ``slots`` —
         free, prefilling, or mid-retire — keep their KV and state bitwise
         (tested as a round-trip property)."""
-        eng = self.eng
+        eng, clock = self.eng, self.clock
         pool = lane.pool
         slots = sorted(lane.rows)         # deterministic gather order
         occ = len(slots)
-        # idx is the view's gather order (slots + padding duplicates);
-        # state rows are gathered by the same idx so row j of the state
-        # always describes row j of the cache view
-        idx, view = pool.compact_view(slots, width)
-        act = lane.active[idx].copy()
-        act[occ:] = False                 # padding rows are inert
-        any_sample = any(lane.temp[s] > 0 for s in slots)
-        sargs = ((jnp.asarray(lane.temp[idx]), jnp.asarray(lane.topk[idx]),
-                  jnp.asarray(lane.seed[idx])) if any_sample
-                 else (None, None, None))
-        toks, emits, state, caches = eng._segment_fn()(
-            eng.params, jnp.asarray(lane.last_tok[idx][:, None]),
-            jnp.asarray(lane.pos[idx][:, None]), view,
-            jnp.asarray(act), jnp.asarray(lane.budget[idx]),
-            jnp.asarray(lane.eos[idx]), *sargs)
-        pool.scatter_back(slots, caches)
-        toks, emits = np.asarray(toks)[:occ], np.asarray(emits)[:occ]
-        st_active = np.asarray(state["active"])[:occ]
-        st_eos = np.asarray(state["eos_hit"])[:occ]
-        lane.last_tok[slots] = np.asarray(state["tok"])[:occ, 0]
-        lane.pos[slots] = np.asarray(state["pos"])[:occ, 0]
-        lane.budget[slots] = np.asarray(state["budget"])[:occ]
-        lane.active[slots] = st_active
+        with clock.phase("dispatch"):
+            # idx is the view's gather order (slots + padding duplicates);
+            # state rows are gathered by the same idx so row j of the state
+            # always describes row j of the cache view
+            idx, view = pool.compact_view(slots, width)
+            act = lane.active[idx].copy()
+            act[occ:] = False                 # padding rows are inert
+            any_sample = any(lane.temp[s] > 0 for s in slots)
+            sargs = ((jnp.asarray(lane.temp[idx]),
+                      jnp.asarray(lane.topk[idx]),
+                      jnp.asarray(lane.seed[idx])) if any_sample
+                     else (None, None, None))
+            toks, emits, state, caches = eng._segment_fn()(
+                eng.params, jnp.asarray(lane.last_tok[idx][:, None]),
+                jnp.asarray(lane.pos[idx][:, None]), view,
+                jnp.asarray(act), jnp.asarray(lane.budget[idx]),
+                jnp.asarray(lane.eos[idx]), *sargs)
+            pool.scatter_back(slots, caches)
+        with clock.phase("device"):
+            jax.block_until_ready((toks, emits, state))
+        with clock.phase("copy"):
+            toks, emits = np.asarray(toks)[:occ], np.asarray(emits)[:occ]
+            st_active = np.asarray(state["active"])[:occ]
+            st_eos = np.asarray(state["eos_hit"])[:occ]
+            lane.last_tok[slots] = np.asarray(state["tok"])[:occ, 0]
+            lane.pos[slots] = np.asarray(state["pos"])[:occ, 0]
+            lane.budget[slots] = np.asarray(state["budget"])[:occ]
+            lane.active[slots] = st_active
         return slots, toks, emits, st_active, st_eos
 
     # ------------------------------------------------- speculative rounds
@@ -747,24 +799,29 @@ class ContinuousScheduler:
         rollback is what re-establishes, for both pools, the invariant
         that positions at or past a row's frontier hold the empty
         sentinel before the next round reads them."""
-        eng = self.eng
+        eng, clock = self.eng, self.clock
         k = eng.ec.spec_k
         pool, dpool = lane.pool, eng._get_draft_pool(lane.bucket)
         slots = sorted(lane.rows)         # deterministic gather order
         occ = len(slots)
         width = pick_tier(occ, eng._tiers)
-        idx, view = pool.compact_view(slots, width)
-        _, dview = dpool.compact_view(slots, width)
-        any_sample = any(lane.temp[s] > 0 for s in slots)
-        sargs = ((jnp.asarray(lane.temp[idx]), jnp.asarray(lane.topk[idx]),
-                  jnp.asarray(lane.seed[idx])) if any_sample
-                 else (None, None, None))
-        drafts, verify, caches, dcaches = eng._spec_round_fn()(
-            eng.params, eng.draft_params,
-            jnp.asarray(lane.last_tok[idx][:, None]),
-            jnp.asarray(lane.pos[idx][:, None]), view, dview, *sargs)
-        drafts = np.asarray(drafts)[:occ]         # (occ, k) proposals
-        verify = np.asarray(verify)[:occ]         # (occ, k+1) target picks
+        with clock.phase("dispatch"):
+            idx, view = pool.compact_view(slots, width)
+            _, dview = dpool.compact_view(slots, width)
+            any_sample = any(lane.temp[s] > 0 for s in slots)
+            sargs = ((jnp.asarray(lane.temp[idx]),
+                      jnp.asarray(lane.topk[idx]),
+                      jnp.asarray(lane.seed[idx])) if any_sample
+                     else (None, None, None))
+            drafts, verify, caches, dcaches = eng._spec_round_fn()(
+                eng.params, eng.draft_params,
+                jnp.asarray(lane.last_tok[idx][:, None]),
+                jnp.asarray(lane.pos[idx][:, None]), view, dview, *sargs)
+        with clock.phase("device"):
+            jax.block_until_ready((drafts, verify))
+        with clock.phase("copy"):
+            drafts = np.asarray(drafts)[:occ]     # (occ, k) proposals
+            verify = np.asarray(verify)[:occ]     # (occ, k+1) target picks
         stat = eng._lane_stat(lane.bucket)
         eng.batch_sizes.append(occ)
         eng._stats["decode_segments"] += 1
@@ -776,41 +833,44 @@ class ContinuousScheduler:
         now = time.perf_counter()
         bounds = np.zeros(occ, np.int32)
         retire = []
-        for j, s in enumerate(slots):
-            row = lane.rows[s]
-            a = 0                  # leading draft tokens the target agreed on
-            while a < k and drafts[j, a] == verify[j, a]:
-                a += 1
-            stat["spec_accepted"] += a
-            # commit the agreements plus one target-selected token (the
-            # correction at the first disagreement, or the bonus token
-            # after a full accept), clamped to the row's budget
-            c = min(a + 1, int(lane.budget[s]))
-            committed = verify[j, :c].tolist()
-            eos, eos_hit = int(lane.eos[s]), False
-            if eos >= 0 and eos in committed:
-                committed = committed[:committed.index(eos) + 1]
-                eos_hit = True
-            c = len(committed)
-            bounds[j] = int(lane.pos[s]) + c
-            lane.last_tok[s] = committed[-1]
-            lane.pos[s] = bounds[j]
-            lane.budget[s] -= c
-            row.toks.extend(committed)
-            row.req.handle._push(committed)
-            pool.lengths[s] = int(bounds[j]) + 1
-            if eos_hit:
-                retire.append((row, FINISH_EOS))
-            elif lane.budget[s] <= 0:
-                retire.append((row, FINISH_LENGTH))
-            elif row.req.handle.cancel_requested:
-                retire.append((row, FINISH_CANCELLED))
+        with clock.phase("publish"):
+            for j, s in enumerate(slots):
+                row = lane.rows[s]
+                a = 0              # leading draft tokens the target agreed on
+                while a < k and drafts[j, a] == verify[j, a]:
+                    a += 1
+                stat["spec_accepted"] += a
+                # commit the agreements plus one target-selected token (the
+                # correction at the first disagreement, or the bonus token
+                # after a full accept), clamped to the row's budget
+                c = min(a + 1, int(lane.budget[s]))
+                committed = verify[j, :c].tolist()
+                eos, eos_hit = int(lane.eos[s]), False
+                if eos >= 0 and eos in committed:
+                    committed = committed[:committed.index(eos) + 1]
+                    eos_hit = True
+                c = len(committed)
+                bounds[j] = int(lane.pos[s]) + c
+                lane.last_tok[s] = committed[-1]
+                lane.pos[s] = bounds[j]
+                lane.budget[s] -= c
+                row.toks.extend(committed)
+                row.req.handle._push(committed)
+                pool.lengths[s] = int(bounds[j]) + 1
+                if eos_hit:
+                    retire.append((row, FINISH_EOS))
+                elif lane.budget[s] <= 0:
+                    retire.append((row, FINISH_LENGTH))
+                elif row.req.handle.cancel_requested:
+                    retire.append((row, FINISH_CANCELLED))
         # scatter before retiring (like _segment_compact): _finish releases
         # slots, and a released slot must not be written afterwards
-        pool.scatter_rollback(slots, caches, bounds)
-        dpool.scatter_rollback(slots, dcaches, bounds)
-        for row, reason in retire:
-            self._finish(lane, row, reason, now)
+        with clock.phase("dispatch"):
+            pool.scatter_rollback(slots, caches, bounds)
+            dpool.scatter_rollback(slots, dcaches, bounds)
+        with clock.phase("publish"):
+            for row, reason in retire:
+                self._finish(lane, row, reason, now)
 
     # ------------------------------------------------------------ retire
     def _resolve(self, r, toks, reason: str, now: float) -> None:  # holds: worker

@@ -46,15 +46,22 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import (decode_segment, decode_step, forward, make_caches,
-                          prefill_chunk, sample_logits, spec_round)
+                          prefill_chunk, sample_logits,
+                          spec_round as _spec_round)
 from repro.quant import params_bytes, quantize_params, validate_kv_quant
 from repro.serving.api import (FINISH_CANCELLED, FINISH_EOS, FINISH_LENGTH,
                                GenerationRequest, GenerationResult, HeadFn,
                                RequestHandle, RequestTiming, SamplingParams)
 from repro.serving import kvcache
+from repro.serving.clock import WorkerClock
 from repro.serving.kvcache import CachePool, _take_slots
 from repro.serving.scheduler import (AdmissionQueue, RequestQueue,
                                      pick_tier, width_tiers)
+
+
+# the batch worker's phases (``WorkerClock``): counters batch_<phase>_ns
+BATCH_PHASES = ("wait", "collect", "pack", "dispatch", "device", "copy",
+                "fanout")
 
 
 class RequestTooLong(ValueError):
@@ -200,14 +207,15 @@ class ServingEngine:
         self.latencies: List[float] = []          # guarded-by: worker
         self.batch_sizes: List[int] = []          # guarded-by: worker
         self.timings: List[RequestTiming] = []    # guarded-by: worker — v2 per-phase breakdowns
-        self._stats = {"decode_segments": 0,      # guarded-by: worker
-                       "joins_mid_flight": 0,
-                       "prefill_batches": 0, "prefill_chunks": 0}
+        self.continuous_active = (        # guarded-by: init
+            engine_cfg.mode == "decoder" and engine_cfg.continuous
+            and engine_cfg.use_scan_decode and engine_cfg.use_cache_pool)
+        # counters metrics() reports at the top level; the worker's phase
+        # clock adds its <prefix>_<phase>_ns keys
+        self._stats = ({"decode_segments": 0, "joins_mid_flight": 0,  # guarded-by: worker
+                        "prefill_batches": 0, "prefill_chunks": 0}
+                       if self.continuous_active else {"batch_served": 0})
         self.lane_stats = {}              # guarded-by: worker — per-lane counters
-        # window() cursors: list lengths + counter values at the last snap
-        self._win_cursor = {"latencies": 0,       # guarded-by: client
-                            "batch_sizes": 0, "timings": 0,
-                            "stats": dict(self._stats), "lanes": {}}
         self._stop = threading.Event()            # guarded-by: threadsafe
         # reentrant: a done-callback attached under the lock can fire
         # synchronously (future cancelled in the attach window) and re-enter
@@ -216,9 +224,6 @@ class ServingEngine:
         self._parked_cancelled = 0             # guarded-by: _submit_lock — phantoms in heap
         self._compiled = {}               # guarded-by: worker
         self._pools = {}                  # guarded-by: worker — bucket -> CachePool
-        self.continuous_active = (        # guarded-by: init
-            engine_cfg.mode == "decoder" and engine_cfg.continuous
-            and engine_cfg.use_scan_decode and engine_cfg.use_cache_pool)
         if engine_cfg.segment_width not in ("adaptive", "fixed"):
             raise ValueError(
                 f"segment_width must be 'adaptive' or 'fixed', got "
@@ -296,9 +301,16 @@ class ServingEngine:
                                      # lane_stats without a lock
             from repro.serving.continuous import ContinuousScheduler
             self._scheduler = ContinuousScheduler(self)  # guarded-by: init
+            self._clock = self._scheduler.clock  # guarded-by: init
             target = self._scheduler.run
         else:
+            self._clock = WorkerClock(self._stats, "batch",  # guarded-by: init
+                                      BATCH_PHASES)
             target = self._run
+        # window() cursors: list lengths + counter values at the last snap
+        self._win_cursor = {"latencies": 0,       # guarded-by: client
+                            "batch_sizes": 0, "timings": 0,
+                            "stats": self._clock.read(), "lanes": {}}
         self._worker = threading.Thread(target=target, daemon=True)  # guarded-by: init
         self._worker.start()
 
@@ -715,7 +727,7 @@ class ServingEngine:
 
     def _encoder_fn(self, bucket: int):  # holds: worker
         if ("enc", bucket) not in self._compiled:
-            def fn(params, tokens, mask):
+            def enc_forward(params, tokens, mask):
                 pos = jnp.broadcast_to(
                     jnp.arange(tokens.shape[1], dtype=jnp.int32),
                     tokens.shape)
@@ -727,7 +739,7 @@ class ServingEngine:
                 if self.head_fn is not None:
                     return self.head_fn(params, hid, mask)
                 return hid
-            self._compiled[("enc", bucket)] = jax.jit(fn)
+            self._compiled[("enc", bucket)] = jax.jit(enc_forward)
         return self._compiled[("enc", bucket)]
 
     # --------------------------------------------------- decoder hot path
@@ -765,7 +777,7 @@ class ServingEngine:
         if "dec_scan" not in self._compiled:
             T = self.ec.max_new_tokens
 
-            def fn(params, toks, lens, caches, temp, topk, seed):
+            def dec_scan(params, toks, lens, caches, temp, topk, seed):
                 logits, caches, _ = forward(self.cfg, params, tokens=toks,
                                             caches=caches, mode="full")
                 # first generated token: per-row logits at the row's real
@@ -781,7 +793,7 @@ class ServingEngine:
                     n_steps=T - 1, temperature=temp, top_k=topk, seed=seed)
                 return jnp.concatenate([tok, rest], axis=1), caches
 
-            self._compiled["dec_scan"] = jax.jit(fn)
+            self._compiled["dec_scan"] = jax.jit(dec_scan)
         return self._compiled["dec_scan"]
 
     def _decode_fns(self):  # holds: worker
@@ -790,12 +802,15 @@ class ServingEngine:
         unroll_periods=False reproduces the seed's scanned-period step
         structure exactly."""
         if "dec" not in self._compiled:
-            self._compiled["dec"] = (
-                jax.jit(lambda p, t, c: forward(self.cfg, p, tokens=t,
-                                                caches=c, mode="full")),
-                jax.jit(lambda p, t, pos, c: decode_step(
-                    self.cfg, p, t, pos, c, unroll_periods=False)),
-            )
+            def dec_prefill_legacy(p, t, c):
+                return forward(self.cfg, p, tokens=t, caches=c, mode="full")
+
+            def dec_step_legacy(p, t, pos, c):
+                return decode_step(self.cfg, p, t, pos, c,
+                                   unroll_periods=False)
+
+            self._compiled["dec"] = (jax.jit(dec_prefill_legacy),
+                                     jax.jit(dec_step_legacy))
         return self._compiled["dec"]
 
     def _prefill_fn(self):  # holds: worker
@@ -803,7 +818,7 @@ class ServingEngine:
         caches and select each row's first token. jit specializes per
         (n_new, bucket) shape."""
         if "cont_prefill" not in self._compiled:
-            def fn(params, toks, lens, caches, temp, topk, seed):
+            def dec_prefill(params, toks, lens, caches, temp, topk, seed):
                 logits, caches, _ = forward(self.cfg, params, tokens=toks,
                                             caches=caches, mode="full")
                 last = jnp.take_along_axis(
@@ -811,7 +826,7 @@ class ServingEngine:
                 tok = sample_logits(last, temperature=temp, top_k=topk,
                                     seed=seed, positions=lens)
                 return tok, caches
-            self._compiled["cont_prefill"] = jax.jit(fn)
+            self._compiled["cont_prefill"] = jax.jit(dec_prefill)
         return self._compiled["cont_prefill"]
 
     def _chunk_fn(self):  # holds: worker
@@ -824,7 +839,8 @@ class ServingEngine:
         chunks except a prompt's last are completely filled). jit
         specializes per (n_fills, chunk_len) shape."""
         if "cont_chunk" not in self._compiled:
-            def fn(params, toks, start, nvalid, caches, temp, topk, seed):
+            def dec_chunk(params, toks, start, nvalid, caches, temp, topk,
+                          seed):
                 C = toks.shape[1]
                 positions = start[:, None] + jnp.arange(C, dtype=jnp.int32)
                 logits, caches, _ = prefill_chunk(
@@ -836,7 +852,7 @@ class ServingEngine:
                 tok = sample_logits(last, temperature=temp, top_k=topk,
                                     seed=seed, positions=start + nvalid)
                 return tok, caches
-            self._compiled["cont_chunk"] = jax.jit(fn)
+            self._compiled["cont_chunk"] = jax.jit(dec_chunk)
         return self._compiled["cont_chunk"]
 
     def _segment_fn(self):  # holds: worker
@@ -847,15 +863,16 @@ class ServingEngine:
         if "cont_segment" not in self._compiled:
             seg = self.ec.decode_segment
 
-            def fn(params, tok, pos, caches, active, budget, eos,
-                   temp, topk, seed):
+            def dec_segment(params, tok, pos, caches, active, budget, eos,
+                            temp, topk, seed):
                 return decode_segment(self.cfg, params, tok, pos, caches,
                                       n_steps=seg, active=active,
                                       budget=budget, eos_id=eos,
                                       temperature=temp, top_k=topk,
                                       seed=seed)
 
-            self._compiled["cont_segment"] = jax.jit(fn, donate_argnums=3)
+            self._compiled["cont_segment"] = jax.jit(dec_segment,
+                                                     donate_argnums=3)
         return self._compiled["cont_segment"]
 
     def _draft_prefill_fn(self):  # holds: worker
@@ -864,12 +881,12 @@ class ServingEngine:
         prompt's last position — and ``return_hidden`` keeps the draft
         lm_head out of the graph. jit specializes per (n_new, bucket)."""
         if "spec_dprefill" not in self._compiled:
-            def fn(dparams, toks, caches):
+            def spec_dprefill(dparams, toks, caches):
                 _, caches, _ = forward(self.draft_cfg, dparams, tokens=toks,
                                        caches=caches, mode="full",
                                        return_hidden=True)
                 return caches
-            self._compiled["spec_dprefill"] = jax.jit(fn)
+            self._compiled["spec_dprefill"] = jax.jit(spec_dprefill)
         return self._compiled["spec_dprefill"]
 
     def _spec_round_fn(self):  # holds: worker
@@ -880,13 +897,13 @@ class ServingEngine:
         if "spec_round" not in self._compiled:
             k = self.ec.spec_k
 
-            def fn(params, dparams, tok, pos, caches, dcaches,
-                   temp, topk, seed):
-                return spec_round(self.cfg, params, self.draft_cfg, dparams,
-                                  tok, pos, caches, dcaches, k=k,
-                                  temperature=temp, top_k=topk, seed=seed)
+            def spec_round(params, dparams, tok, pos, caches, dcaches,
+                           temp, topk, seed):
+                return _spec_round(self.cfg, params, self.draft_cfg,
+                                   dparams, tok, pos, caches, dcaches, k=k,
+                                   temperature=temp, top_k=topk, seed=seed)
 
-            self._compiled["spec_round"] = jax.jit(fn,
+            self._compiled["spec_round"] = jax.jit(spec_round,
                                                    donate_argnums=(4, 5))
         return self._compiled["spec_round"]
 
@@ -1008,35 +1025,47 @@ class ServingEngine:
             self._release_caches(handle)
 
     def _serve_batch(self, reqs: List[_Request]):  # holds: worker
+        # only the worker moves its clock and counts its batches; a batch
+        # served on another thread (warm-up) times its phases on a
+        # throwaway clock
+        on_worker = threading.current_thread() is self._worker
+        clock = (self._clock if on_worker
+                 else WorkerClock({}, "batch", BATCH_PHASES))
         # claim each future (concurrent.futures protocol): a client-side
         # cancel() that won between enqueue and here drops the request
         # instead of poisoning set_result for the whole batch
         reqs = [r for r in reqs if r.future.set_running_or_notify_cancel()]
         if not reqs:
             return
-        lens = [len(r.tokens) for r in reqs]
-        bucket = self._bucket(max(lens))
-        B = len(reqs)
-        toks = np.zeros((B, bucket), np.int32)
-        mask = np.zeros((B, bucket), bool)
-        for i, r in enumerate(reqs):
-            toks[i, :len(r.tokens)] = r.tokens
-            mask[i, :len(r.tokens)] = True
+        with clock.phase("pack"):
+            lens = [len(r.tokens) for r in reqs]
+            bucket = self._bucket(max(lens))
+            B = len(reqs)
+            toks = np.zeros((B, bucket), np.int32)
+            mask = np.zeros((B, bucket), bool)
+            for i, r in enumerate(reqs):
+                toks[i, :len(r.tokens)] = r.tokens
+                mask[i, :len(r.tokens)] = True
 
         if self.ec.mode == "encoder":
-            out = self._encoder_fn(bucket)(self.params, jnp.asarray(toks),
-                                           jnp.asarray(mask))
-            out = jax.device_get(out)
-            # record samples BEFORE resolving futures: a client whose
-            # .result() returns must find its sample in metrics()/window()
-            self._record_batch(reqs)
-            for i, r in enumerate(reqs):
-                r.future.set_result(jax.tree.map(lambda x: x[i], out))
-        else:
-            t_serve = time.perf_counter()
-            gen, emits, eos_hit = self._serve_decoder(toks, lens, bucket,
-                                                      reqs)
-            t_done = time.perf_counter()
+            with clock.phase("dispatch"):
+                out = self._encoder_fn(bucket)(
+                    self.params, jnp.asarray(toks), jnp.asarray(mask))
+            with clock.phase("device"):
+                jax.block_until_ready(out)
+            with clock.phase("copy"):
+                out = jax.device_get(out)
+            with clock.phase("fanout"):
+                # record samples BEFORE resolving futures: a client whose
+                # .result() returns must find its sample in metrics()/window()
+                self._record_batch(reqs, on_worker)
+                for i, r in enumerate(reqs):
+                    r.future.set_result(jax.tree.map(lambda x: x[i], out))
+            return
+        t_serve = time.perf_counter()
+        gen, emits, eos_hit = self._serve_decoder(toks, lens, bucket, reqs)
+        t_done = time.perf_counter()
+        with clock.phase("fanout"):
             timings = []
             for r in reqs:
                 timing = RequestTiming(queue_s=t_serve - r.t_submit,
@@ -1045,7 +1074,7 @@ class ServingEngine:
                 timings.append(timing)
                 if r.handle is not None:
                     self.timings.append(timing)
-            self._record_batch(reqs)
+            self._record_batch(reqs, on_worker)
             for i, r in enumerate(reqs):
                 if r.handle is None:    # legacy raw-batch caller
                     r.future.set_result(gen[i])
@@ -1060,28 +1089,35 @@ class ServingEngine:
                     tokens=row, finish_reason=reason, timing=timings[i],
                     request_id=r.handle.request.request_id))
 
-    def _record_batch(self, reqs: List[_Request]) -> None:  # holds: worker
+    def _record_batch(self, reqs: List[_Request],  # holds: worker
+                      on_worker: bool) -> None:
         now = time.perf_counter()
+        if on_worker:
+            self._stats["batch_served"] += 1
         self.batch_sizes.append(len(reqs))
         for r in reqs:
             self.latencies.append(now - r.t_submit)
 
     def _run(self):  # holds: worker
+        clock = self._clock
         while not self._stop.is_set():
             try:
-                first = self._q.get(timeout=0.05)
+                with clock.phase("wait"):
+                    first = self._q.get(timeout=0.05)
             except queue.Empty:
                 continue
             batch = [first]
-            deadline = time.perf_counter() + self.ec.batch_window_ms / 1e3
-            while len(batch) < self.ec.max_batch:
-                remaining = deadline - time.perf_counter()
-                if remaining <= 0:
-                    break
-                try:
-                    batch.append(self._q.get(timeout=remaining))
-                except queue.Empty:
-                    break
+            with clock.phase("collect"):
+                deadline = (time.perf_counter()
+                            + self.ec.batch_window_ms / 1e3)
+                while len(batch) < self.ec.max_batch:
+                    remaining = deadline - time.perf_counter()
+                    if remaining <= 0:
+                        break
+                    try:
+                        batch.append(self._q.get(timeout=remaining))
+                    except queue.Empty:
+                        break
             try:
                 self._serve_batch(batch)
             except Exception as e:  # pragma: no cover - surfaced to client
@@ -1186,8 +1222,6 @@ class ServingEngine:
         m["batch_size_mean"] = (float(np.mean(batch_sizes))
                                 if batch_sizes else 0.0)
         if timings:
-            m["queue_wait_mean_s"] = float(
-                np.mean([t.queue_s for t in timings]))
             m["prefill_mean_s"] = float(
                 np.mean([t.prefill_s for t in timings]))
             m["decode_mean_s"] = float(
@@ -1195,25 +1229,28 @@ class ServingEngine:
         if self.continuous_active:
             # batch_sizes holds per-segment occupancy in continuous mode
             m["batch_occupancy_mean"] = m["batch_size_mean"]
-            m.update(stats)
+        m.update(stats)
         return m
 
     def metrics(self) -> dict:
         """Cumulative serving stats since engine start. With no completed
         requests the latency percentiles are None (never fabricated from a
-        zero sample). Continuous engines additionally report per-lane
-        counters under ``'lanes'`` (bucket -> segments / occupancy mean /
-        joins / prefill chunks / compacted-segment count / ``tier_hist``,
-        the histogram of decode-segment widths the lane actually ran) and
-        ``'jit_compiles'`` (compiled engine specializations so far).
-        ``window()`` gives the same shape for the span since the previous
-        ``window()`` call."""
+        zero sample). ``'jit_compiles'`` counts compiled engine
+        specializations so far. The worker's phase clock (``WorkerClock``)
+        reports integer nanoseconds at the top level, the phase in progress
+        included: ``batch_<phase>_ns`` and ``batch_served`` for the batch
+        worker, ``cont_<phase>_ns`` for the continuous scheduler. Continuous
+        engines additionally report per-lane counters under ``'lanes'``
+        (bucket -> segments / occupancy mean / joins / prefill chunks /
+        compacted-segment count / ``tier_hist``, the histogram of
+        decode-segment widths the lane actually ran). ``window()`` gives the
+        same shape for the span since the previous ``window()`` call."""
         m = self._aggregate(self.latencies, self.batch_sizes, self.timings,
-                            self._stats)
+                            self._clock.read())
         m["weight_bytes"] = self._weight_bytes
+        m["jit_compiles"] = self._jit_compiles()
         if self.continuous_active:
             m["lanes"] = self._lane_view(self.lane_stats)
-            m["jit_compiles"] = self._jit_compiles()
         if self._admission is not None:
             adm = self._admission.snapshot()   # consistent read under _lock
             m["admission_peak_queue"] = adm.queued_peak
@@ -1233,7 +1270,7 @@ class ServingEngine:
         cur = self._win_cursor
         i_lat, i_bs, i_tim = (len(self.latencies), len(self.batch_sizes),
                               len(self.timings))
-        stats_now = dict(self._stats)
+        stats_now = self._clock.read()
         # per-key copy: tier_hist is a nested dict the scheduler mutates
         lanes_now = {b: {k: (dict(v) if isinstance(v, dict) else v)
                          for k, v in s.items()}
@@ -1248,16 +1285,11 @@ class ServingEngine:
                             {k: v - cur["stats"].get(k, 0)
                              for k, v in stats_now.items()})
         m["weight_bytes"] = self._weight_bytes     # gauge, not diffed
+        compiles = self._jit_compiles()
+        m["jit_compiles"] = compiles - cur.get("jit_compiles", 0)
         if self.continuous_active:
             m["lanes"] = self._lane_view(lanes_now, cur.get("lanes"))
-            compiles = self._jit_compiles()
-            m["jit_compiles"] = compiles - cur.get("jit_compiles", 0)
-            self._win_cursor = {"latencies": i_lat, "batch_sizes": i_bs,
-                                "timings": i_tim, "stats": stats_now,
-                                "lanes": lanes_now,
-                                "jit_compiles": compiles}
-        else:
-            self._win_cursor = {"latencies": i_lat, "batch_sizes": i_bs,
-                                "timings": i_tim, "stats": stats_now,
-                                "lanes": lanes_now}
+        self._win_cursor = {"latencies": i_lat, "batch_sizes": i_bs,
+                            "timings": i_tim, "stats": stats_now,
+                            "lanes": lanes_now, "jit_compiles": compiles}
         return m
