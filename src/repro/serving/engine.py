@@ -4,7 +4,9 @@ Nginx + Flask + GECToR stack (Fig. 6).
 Two modes, matching the two model kinds in the repo:
   * 'encoder' — one bidirectional forward per request batch (GECToR: the
     paper's workload). Requests are token sequences; responses are the
-    model's per-token outputs (edit tags for GECToR).
+    model's per-token outputs (edit tags for GECToR). The worker keeps
+    up to two batches going: the next batch is dispatched while the
+    last one's outputs are still on their way to the host.
   * 'decoder' — prefill + autoregressive decode with a KV-cache pool.
 
 Decoder requests go through the typed v2 lifecycle (``serving.api``):
@@ -39,7 +41,7 @@ import queue
 import threading
 import time
 from concurrent.futures import Future
-from typing import List, Optional
+from typing import List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -52,7 +54,7 @@ from repro.quant import params_bytes, quantize_params, validate_kv_quant
 from repro.serving.api import (FINISH_CANCELLED, FINISH_EOS, FINISH_LENGTH,
                                GenerationRequest, GenerationResult, HeadFn,
                                RequestHandle, RequestTiming, SamplingParams)
-from repro.serving import kvcache
+from repro.serving import hostmem, kvcache
 from repro.serving.clock import WorkerClock
 from repro.serving.kvcache import CachePool, _take_slots
 from repro.serving.scheduler import (AdmissionQueue, RequestQueue,
@@ -152,6 +154,13 @@ class _Request:
     t_prefill_done: float = 0.0
 
 
+class _Launched(NamedTuple):
+    """An encoder batch dispatched by ``_launch_batch`` and not finished."""
+    reqs: List[_Request]
+    out: object                       # enc_forward's outputs, on the device
+    on_worker: bool
+
+
 def _trim_host(gen: np.ndarray, eos: np.ndarray, budget: np.ndarray):
     """Host-side emission trim for the batch-at-a-time path: a row's output
     ends at its budget or just after its first eos token. Token-identical
@@ -214,7 +223,8 @@ class ServingEngine:
         # clock adds its <prefix>_<phase>_ns keys
         self._stats = ({"decode_segments": 0, "joins_mid_flight": 0,  # guarded-by: worker
                         "prefill_batches": 0, "prefill_chunks": 0}
-                       if self.continuous_active else {"batch_served": 0})
+                       if self.continuous_active
+                       else {"batch_served": 0, "batch_overlapped": 0})
         self.lane_stats = {}              # guarded-by: worker — per-lane counters
         self._stop = threading.Event()            # guarded-by: threadsafe
         # reentrant: a done-callback attached under the lock can fire
@@ -1031,37 +1041,15 @@ class ServingEngine:
         on_worker = threading.current_thread() is self._worker
         clock = (self._clock if on_worker
                  else WorkerClock({}, "batch", BATCH_PHASES))
-        # claim each future (concurrent.futures protocol): a client-side
-        # cancel() that won between enqueue and here drops the request
-        # instead of poisoning set_result for the whole batch
-        reqs = [r for r in reqs if r.future.set_running_or_notify_cancel()]
-        if not reqs:
-            return
-        with clock.phase("pack"):
-            lens = [len(r.tokens) for r in reqs]
-            bucket = self._bucket(max(lens))
-            B = len(reqs)
-            toks = np.zeros((B, bucket), np.int32)
-            mask = np.zeros((B, bucket), bool)
-            for i, r in enumerate(reqs):
-                toks[i, :len(r.tokens)] = r.tokens
-                mask[i, :len(r.tokens)] = True
-
         if self.ec.mode == "encoder":
-            with clock.phase("dispatch"):
-                out = self._encoder_fn(bucket)(
-                    self.params, jnp.asarray(toks), jnp.asarray(mask))
-            with clock.phase("device"):
-                jax.block_until_ready(out)
-            with clock.phase("copy"):
-                out = jax.device_get(out)
-            with clock.phase("fanout"):
-                # record samples BEFORE resolving futures: a client whose
-                # .result() returns must find its sample in metrics()/window()
-                self._record_batch(reqs, on_worker)
-                for i, r in enumerate(reqs):
-                    r.future.set_result(jax.tree.map(lambda x: x[i], out))
+            launched = self._launch_batch(reqs, clock, on_worker)
+            if launched is not None:
+                self._finish_batch(launched, clock)
             return
+        packed = self._pack(reqs, clock)
+        if packed is None:
+            return
+        reqs, lens, bucket, toks, _ = packed
         t_serve = time.perf_counter()
         gen, emits, eos_hit = self._serve_decoder(toks, lens, bucket, reqs)
         t_done = time.perf_counter()
@@ -1089,6 +1077,58 @@ class ServingEngine:
                     tokens=row, finish_reason=reason, timing=timings[i],
                     request_id=r.handle.request.request_id))
 
+    def _pack(self, reqs: List[_Request], clock):  # holds: worker
+        """Claim each future and pad the batch to its bucket: ``(reqs,
+        lens, bucket, toks, mask)``, or None when every request was
+        cancelled."""
+        # claim each future (concurrent.futures protocol): a client-side
+        # cancel() that won between enqueue and here drops the request
+        # instead of poisoning set_result for the whole batch
+        reqs = [r for r in reqs if r.future.set_running_or_notify_cancel()]
+        if not reqs:
+            return None
+        with clock.phase("pack"):
+            lens = [len(r.tokens) for r in reqs]
+            bucket = self._bucket(max(lens))
+            toks = np.zeros((len(reqs), bucket), np.int32)
+            mask = np.zeros((len(reqs), bucket), bool)
+            for i, r in enumerate(reqs):
+                toks[i, :len(r.tokens)] = r.tokens
+                mask[i, :len(r.tokens)] = True
+        return reqs, lens, bucket, toks, mask
+
+    def _launch_batch(self, reqs: List[_Request], clock,  # holds: worker
+                      on_worker: bool) -> Optional[_Launched]:
+        """Encoder: pack and dispatch ``enc_forward``, and start each
+        output's copy to the host, which then runs as soon as the forward
+        ends; ``_finish_batch`` resolves the futures."""
+        packed = self._pack(reqs, clock)
+        if packed is None:
+            return None
+        reqs, _, bucket, toks, mask = packed
+        with clock.phase("dispatch"):
+            out = self._encoder_fn(bucket)(
+                self.params, jnp.asarray(toks), jnp.asarray(mask))
+            for leaf in jax.tree.leaves(out):
+                leaf.copy_to_host_async()
+        return _Launched(reqs, out, on_worker)
+
+    def _finish_batch(self, b: _Launched, clock) -> None:  # holds: worker
+        with clock.phase("device"):
+            jax.block_until_ready(b.out)
+        with clock.phase("copy"):
+            out = jax.device_get(b.out)
+            # keep these host buffers mapped once freed, so the next
+            # batch's copy faults in no page
+            hostmem.keep(max((x.nbytes for x in jax.tree.leaves(out)),
+                             default=0))
+        with clock.phase("fanout"):
+            # record samples BEFORE resolving futures: a client whose
+            # .result() returns must find its sample in metrics()/window()
+            self._record_batch(b.reqs, b.on_worker)
+            for i, r in enumerate(b.reqs):
+                r.future.set_result(jax.tree.map(lambda x: x[i], out))
+
     def _record_batch(self, reqs: List[_Request],  # holds: worker
                       on_worker: bool) -> None:
         now = time.perf_counter()
@@ -1098,32 +1138,96 @@ class ServingEngine:
         for r in reqs:
             self.latencies.append(now - r.t_submit)
 
-    def _run(self):  # holds: worker
+    def _next_batch(self, block: bool):  # holds: worker
+        """The next batch off the queue; None when no request came.
+        ``block`` waits up to 50 ms for a first request, then fills the
+        batch within ``batch_window_ms`` of it. Without ``block`` a batch
+        is in flight, and finishing it is the wait: only what is queued
+        already is taken, up to ``max_batch``."""
         clock = self._clock
-        while not self._stop.is_set():
-            try:
-                with clock.phase("wait"):
-                    first = self._q.get(timeout=0.05)
-            except queue.Empty:
-                continue
-            batch = [first]
+        if not block:
+            batch = []
             with clock.phase("collect"):
-                deadline = (time.perf_counter()
-                            + self.ec.batch_window_ms / 1e3)
                 while len(batch) < self.ec.max_batch:
-                    remaining = deadline - time.perf_counter()
-                    if remaining <= 0:
-                        break
                     try:
-                        batch.append(self._q.get(timeout=remaining))
+                        batch.append(self._q.get_nowait())
                     except queue.Empty:
                         break
+            return batch or None
+        try:
+            with clock.phase("wait"):
+                first = self._q.get(timeout=0.05)
+        except queue.Empty:
+            return None
+        batch = [first]
+        with clock.phase("collect"):
+            deadline = time.perf_counter() + self.ec.batch_window_ms / 1e3
+            while len(batch) < self.ec.max_batch:
+                remaining = deadline - time.perf_counter()
+                if remaining <= 0:
+                    break
+                try:
+                    batch.append(self._q.get(timeout=remaining))
+                except queue.Empty:
+                    break
+        return batch
+
+    @staticmethod
+    def _fail(reqs: List[_Request], e: Exception) -> None:
+        for r in reqs:
+            if not r.future.done():
+                r.future.set_exception(e)
+
+    def _run(self):  # holds: worker
+        if self.ec.mode == "encoder":
+            self._run_encoder()
+            return
+        while not self._stop.is_set():
+            batch = self._next_batch(block=True)
+            if batch is None:
+                continue
             try:
                 self._serve_batch(batch)
             except Exception as e:  # pragma: no cover - surfaced to client
-                for r in batch:
-                    if not r.future.done():
-                        r.future.set_exception(e)
+                self._fail(batch, e)
+
+    def _run_encoder(self):  # holds: worker
+        """Two-deep pipeline: batch k+1 is taken from what is queued,
+        packed and dispatched before batch k is finished, so k's copy to
+        the host runs beside k+1's dispatch and forward. With nothing
+        queued, the batch in flight is finished rather than kept waiting
+        for company. Futures resolve in launch order; a batch that fails
+        fails only its own."""
+        clock = self._clock
+        inflight = None
+
+        def finish(b):
+            try:
+                self._finish_batch(b, clock)
+            except Exception as e:  # surfaced to the batch's clients
+                self._fail(b.reqs, e)
+
+        try:
+            while not self._stop.is_set():
+                batch = self._next_batch(block=inflight is None)
+                if batch is None:
+                    if inflight is not None:
+                        finish(inflight)
+                        inflight = None
+                    continue
+                try:
+                    launched = self._launch_batch(batch, clock, True)
+                except Exception as e:  # surfaced to the batch's clients
+                    self._fail(batch, e)
+                    launched = None
+                if inflight is not None:
+                    if launched is not None:
+                        self._stats["batch_overlapped"] += 1
+                    finish(inflight)
+                inflight = launched
+        finally:
+            if inflight is not None:
+                finish(inflight)
 
     # ------------------------------------------------------------ metrics
     def _lane_stat(self, bucket: int) -> dict:  # holds: worker
@@ -1238,13 +1342,15 @@ class ServingEngine:
         zero sample). ``'jit_compiles'`` counts compiled engine
         specializations so far. The worker's phase clock (``WorkerClock``)
         reports integer nanoseconds at the top level, the phase in progress
-        included: ``batch_<phase>_ns`` and ``batch_served`` for the batch
-        worker, ``cont_<phase>_ns`` for the continuous scheduler. Continuous
-        engines additionally report per-lane counters under ``'lanes'``
-        (bucket -> segments / occupancy mean / joins / prefill chunks /
-        compacted-segment count / ``tier_hist``, the histogram of
-        decode-segment widths the lane actually ran). ``window()`` gives the
-        same shape for the span since the previous ``window()`` call."""
+        included: ``batch_<phase>_ns`` for the batch worker, with
+        ``batch_served`` and ``batch_overlapped`` (the encoder batches it
+        launched while another was in flight), ``cont_<phase>_ns`` for the
+        continuous scheduler. Continuous engines additionally report
+        per-lane counters under ``'lanes'`` (bucket -> segments / occupancy
+        mean / joins / prefill chunks / compacted-segment count /
+        ``tier_hist``, the histogram of decode-segment widths the lane
+        actually ran). ``window()`` gives the same shape for the span since
+        the previous ``window()`` call."""
         m = self._aggregate(self.latencies, self.batch_sizes, self.timings,
                             self._clock.read())
         m["weight_bytes"] = self._weight_bytes
